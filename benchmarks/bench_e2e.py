@@ -39,6 +39,8 @@ from repro.data import (
 )
 from repro.data.transfer import DeviceDecode
 
+from . import vit
+
 OUT_PATH = pathlib.Path(__file__).resolve().parent.parent / "BENCH_e2e.json"
 
 SEQ_LEN, LM_BATCH = 64, 8
@@ -47,11 +49,7 @@ STEPS = 20
 # -- ViT-B/16-shaped image workload (true /16 patching; width/depth scaled
 # -- for a CPU box — the tokens-per-image and data path are the real thing)
 IMG_HW = (224, 224)
-PATCH = 16
-D_MODEL = 128
-DEPTH = 2
-HEADS = 4
-N_CLASSES = 10
+VIT = vit.ViTConfig(d_model=128, depth=2, heads=4, mlp=512, n_classes=10)
 IMG_BATCH = 8
 IMG_N = 64  # dataset size → 8 batches/epoch
 IMG_STEPS = 24
@@ -157,74 +155,6 @@ def _lm_rows(steps: int) -> list[tuple[str, float, str]]:
     return rows
 
 
-# ---------------------------------------------------------------------------
-# ViT-shaped synthetic step (self-contained: params are a plain pytree)
-# ---------------------------------------------------------------------------
-def _vit_init(key, hw: tuple[int, int]):
-    n_tok = (hw[0] // PATCH) * (hw[1] // PATCH)
-    in_dim = 3 * PATCH * PATCH
-    ks = iter(jax.random.split(key, 3 + 8 * DEPTH))
-    g = lambda shape, s: (jax.random.normal(next(ks), shape, jnp.float32) * s)
-    params = {
-        "proj": g((in_dim, D_MODEL), in_dim**-0.5),
-        "pos": g((n_tok, D_MODEL), 0.02),
-        "head": g((D_MODEL, N_CLASSES), D_MODEL**-0.5),
-        "blocks": [
-            {
-                "ln1": jnp.ones((D_MODEL,)),
-                "ln2": jnp.ones((D_MODEL,)),
-                "qkv": g((D_MODEL, 3 * D_MODEL), D_MODEL**-0.5),
-                "attn_o": g((D_MODEL, D_MODEL), D_MODEL**-0.5),
-                "mlp_up": g((D_MODEL, 4 * D_MODEL), D_MODEL**-0.5),
-                "mlp_dn": g((4 * D_MODEL, D_MODEL), (4 * D_MODEL) ** -0.5),
-            }
-            for _ in range(DEPTH)
-        ],
-    }
-    return params
-
-
-def _vit_apply(params, x):  # x: (B, 3, H, W) — the device-decode output layout
-    b, c, h, w = x.shape
-    nh, nw = h // PATCH, w // PATCH
-    x = x.astype(jnp.float32)
-    x = x.reshape(b, c, nh, PATCH, nw, PATCH)
-    x = x.transpose(0, 2, 4, 1, 3, 5).reshape(b, nh * nw, c * PATCH * PATCH)
-    hdn = x @ params["proj"] + params["pos"]
-
-    def ln(v, gamma):
-        mu = v.mean(-1, keepdims=True)
-        var = ((v - mu) ** 2).mean(-1, keepdims=True)
-        return (v - mu) * jax.lax.rsqrt(var + 1e-6) * gamma
-
-    for blk in params["blocks"]:
-        y = ln(hdn, blk["ln1"])
-        qkv = (y @ blk["qkv"]).reshape(b, -1, 3, HEADS, D_MODEL // HEADS)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D_MODEL // HEADS) ** -0.5
-        a = jax.nn.softmax(s, axis=-1)
-        y = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(b, -1, D_MODEL)
-        hdn = hdn + y @ blk["attn_o"]
-        y = ln(hdn, blk["ln2"])
-        hdn = hdn + jax.nn.gelu(y @ blk["mlp_up"]) @ blk["mlp_dn"]
-    return hdn.mean(1) @ params["head"]
-
-
-def _make_vit_step():
-    @jax.jit
-    def step(params, x, labels):
-        def loss_fn(p):
-            logits = _vit_apply(p, x)
-            logp = jax.nn.log_softmax(logits)
-            return -jnp.take_along_axis(logp, labels[:, None], axis=1).mean()
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        params = jax.tree_util.tree_map(lambda a, g: a - 1e-3 * g, params, grads)
-        return params, loss
-
-    return step
-
-
 def _proc_cpu_s() -> float:
     """Process CPU seconds (utime + stime, all threads) from /proc."""
     parts = open("/proc/self/stat").read().split()
@@ -244,7 +174,7 @@ def _host_decode_tail(images: np.ndarray) -> jax.Array:
 
 def _image_loader(ds, hw, *, device_decode: bool, epochs):
     dd = (
-        DeviceDecode(mean=MEAN, std=STD, use_pallas="auto")
+        DeviceDecode(mean=MEAN, std=STD)
         if device_decode
         else None
     )
@@ -267,9 +197,9 @@ def _measure_starvation(ds, hw, steps: int) -> dict:
     """Live-fed ViT training: the loader runs concurrently with the step;
     the gate is the accumulated time the step spent waiting in get_items
     after warmup (≤ 1% of wall = the loader never starves the step)."""
-    params = _vit_init(jax.random.PRNGKey(0), hw)
-    step = _make_vit_step()
-    labels = jnp.asarray(np.arange(IMG_BATCH) % N_CLASSES, jnp.int32)
+    params = vit.init(VIT, jax.random.PRNGKey(0), hw)
+    step = vit.make_step(VIT)
+    labels = jnp.asarray(np.arange(IMG_BATCH) % VIT.n_classes, jnp.int32)
     pipe = _image_loader(ds, hw, device_decode=True, epochs=None)
     stash: deque = deque()
     wait = 0.0
@@ -366,8 +296,9 @@ def _image_section(smoke: bool) -> dict:
     speedup = host["cpu_s"] / max(wire["cpu_s"], 1e-9)
     return {
         "workload": {
-            "hw": list(hw), "patch": PATCH, "tokens_per_image": (hw[0] // PATCH) * (hw[1] // PATCH),
-            "d_model": D_MODEL, "depth": DEPTH, "heads": HEADS,
+            "hw": list(hw), "patch": VIT.patch,
+            "tokens_per_image": (hw[0] // VIT.patch) * (hw[1] // VIT.patch),
+            "d_model": VIT.d_model, "depth": VIT.depth, "heads": VIT.heads,
             "batch_size": IMG_BATCH, "dataset_items": n, "steps": steps,
         },
         "starvation": starv,
@@ -430,6 +361,9 @@ def check_gate() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--gate" in sys.argv:
         sys.exit(check_gate())
     for r in run("--smoke" in sys.argv):

@@ -57,5 +57,8 @@ def run() -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

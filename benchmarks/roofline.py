@@ -170,10 +170,8 @@ def run(dryrun_dir: str = "experiments/dryrun") -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
-    # CSV rows, not the markdown table: run.py --smoke drives this module
-    # as a subprocess and parses "name,us,derived" lines — markdown output
-    # would silently parse to zero rows (run() still writes the md table
-    # to experiments/roofline.md).
+    # CSV rows like every bench, not the markdown table (run() still
+    # writes the md table to experiments/roofline.md).
     import sys
 
     for r in run(*sys.argv[1:2]):

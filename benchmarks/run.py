@@ -4,6 +4,10 @@ Prints ``name,us_per_call,derived`` CSV (assignment contract).  The roofline
 rows are derived from the dry-run artifacts under experiments/dryrun (run
 ``python -m repro.launch.dryrun`` first to refresh them).
 
+Every bench runs in a child process of its own (``--bench NAME``), and
+this parent never imports JAX: a device belongs to one process at a time,
+so a parent that had touched it would leave its children none.
+
 ``--smoke`` runs each registered bench as a ~2-second CI sanity check:
 modules whose ``run`` accepts a ``smoke`` flag shrink their workload; the
 rest are given a 2-second soft budget and reported as ``_SMOKE_TIMEOUT``
@@ -18,13 +22,14 @@ import inspect
 import os
 import subprocess
 import sys
+import threading
+import time
 import traceback
 
 SMOKE_BUDGET_S = 2.0
-# Modules without a smoke flag run in a kill-at-budget subprocess; the
-# budget is padded by the interpreter/jax import time the in-process path
-# doesn't pay.  A killed bench can't keep running behind the harness's
-# back, so later rows are never contended.
+# A bench without a smoke flag ends its own process at the budget, padded
+# by the interpreter/jax import time; a killed bench can't keep running
+# behind the harness's back, so later rows are never contended.
 SMOKE_IMPORT_GRACE_S = 45.0
 
 #: (label, module) registry; modules are imported lazily and individually so
@@ -48,41 +53,48 @@ REGISTRY = [
 ]
 
 
-def _run_module(mod, mod_name: str, smoke: bool):
-    """Invoke the bench honoring the smoke budget.
+def _tag(mod_name: str) -> str:
+    label = dict((m, lbl) for lbl, m in REGISTRY)[mod_name]
+    return label.replace(" ", "_")
 
-    Returns ``("ok", rows)`` or ``("timeout", None)``.  Smoke-aware modules
-    shrink their own workload in-process; the rest run in a subprocess that
-    is killed at the budget (plus import grace), so an over-budget bench
-    can never keep executing alongside later ones."""
-    accepts_smoke = "smoke" in inspect.signature(mod.run).parameters
-    if not smoke:
-        return "ok", mod.run()
-    if accepts_smoke:
-        return "ok", mod.run(smoke=True)
+
+def _run_bench(mod_name: str, smoke: bool) -> int:
+    """Child side: import one bench, run it, print its CSV rows.
+
+    Returns the exit code: 0 on success (and on a smoke timeout, which is
+    a row, not a failure), 1 when the import or the run raised."""
+    t_start = time.monotonic()
+    tag = _tag(mod_name)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     try:
-        proc = subprocess.run(
-            [sys.executable, "-m", f"{__package__}.{mod_name}"],
-            capture_output=True,
-            text=True,
-            timeout=SMOKE_BUDGET_S + SMOKE_IMPORT_GRACE_S,
-            env=os.environ.copy(),
-        )
-    except subprocess.TimeoutExpired:
-        return "timeout", None
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"bench subprocess exited {proc.returncode}: {proc.stderr[-400:]}"
-        )
-    rows = []
-    for line in proc.stdout.splitlines():
-        parts = line.split(",", 2)
-        if len(parts) == 3:
-            try:
-                rows.append((parts[0], float(parts[1]), parts[2]))
-            except ValueError:
-                pass  # stray print, not a CSV row
-    return "ok", rows
+        mod = importlib.import_module(f".{mod_name}", package=__package__)
+    except Exception as e:
+        traceback.print_exc()
+        print(f"{tag}_IMPORT_FAILED,0,{e!r}", flush=True)
+        return 1
+    accepts_smoke = "smoke" in inspect.signature(mod.run).parameters
+    if smoke and not accepts_smoke:
+
+        def over_budget() -> None:
+            print(f"{tag}_SMOKE_TIMEOUT,0,killed_over_{SMOKE_BUDGET_S}s_budget",
+                  flush=True)
+            os._exit(0)
+
+        budget = SMOKE_BUDGET_S + SMOKE_IMPORT_GRACE_S - (time.monotonic() - t_start)
+        timer = threading.Timer(max(budget, 0.0), over_budget)
+        timer.daemon = True
+        timer.start()
+    try:
+        rows = mod.run(smoke=True) if smoke and accepts_smoke else mod.run()
+    except Exception as e:
+        traceback.print_exc()
+        print(f"{tag}_FAILED,0,{e!r}", flush=True)
+        return 1
+    for name, us, derived in rows:
+        print(f"{name},{us:.1f},{derived}", flush=True)
+    return 0
 
 
 def main(argv: list[str] | None = None) -> None:
@@ -92,30 +104,21 @@ def main(argv: list[str] | None = None) -> None:
         action="store_true",
         help="~2s per bench: CI sanity check, not a measurement",
     )
+    parser.add_argument(
+        "--bench", choices=[m for _, m in REGISTRY],
+        help="run this one bench in this process (what the harness's children do)",
+    )
     args = parser.parse_args(argv)
+    if args.bench:
+        sys.exit(_run_bench(args.bench, args.smoke))
 
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = 0
-    for label, mod_name in REGISTRY:
-        tag = label.replace(" ", "_")
-        try:
-            mod = importlib.import_module(f".{mod_name}", package=__package__)
-        except Exception as e:
-            failures += 1
-            traceback.print_exc()
-            print(f"{tag}_IMPORT_FAILED,0,{e!r}")
-            continue
-        try:
-            status, rows = _run_module(mod, mod_name, args.smoke)
-            if status == "timeout":
-                print(f"{tag}_SMOKE_TIMEOUT,0,killed_over_{SMOKE_BUDGET_S}s_budget")
-                continue
-            for name, us, derived in rows:
-                print(f"{name},{us:.1f},{derived}")
-        except Exception as e:
-            failures += 1
-            traceback.print_exc()
-            print(f"{tag}_FAILED,0,{e!r}")
+    for _label, mod_name in REGISTRY:
+        cmd = [sys.executable, "-m", f"{__package__}.run", "--bench", mod_name]
+        if args.smoke:
+            cmd.append("--smoke")
+        failures += subprocess.run(cmd).returncode != 0
     if failures:
         sys.exit(1)
 

@@ -178,7 +178,6 @@ from concurrent.futures import Executor, Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, AsyncIterable, Callable, Iterable
 
-from ._compat import TaskGroup
 from .errors import OnError, PipelineFailure
 from .queues import EOF, MonitoredQueue
 from .stats import StageStats
@@ -983,7 +982,7 @@ class StageRuntime:
                 await emit(await t)
 
         try:
-            async with TaskGroup() as tg:
+            async with asyncio.TaskGroup() as tg:
                 tg.create_task(reader(), name=f"{self.spec.name}:reader")
                 tg.create_task(emitter(), name=f"{self.spec.name}:emitter")
         except BaseException:
@@ -1008,7 +1007,7 @@ class StageRuntime:
             if v is not _DROPPED:
                 await self._emit(v)
 
-        async def worker(unit: Any, tg: TaskGroup) -> None:
+        async def worker(unit: Any, tg: asyncio.TaskGroup) -> None:
             try:
                 outcome = await run(unit)
                 if slowlane:
@@ -1029,7 +1028,7 @@ class StageRuntime:
             finally:
                 sem.release()
 
-        async with TaskGroup() as tg:
+        async with asyncio.TaskGroup() as tg:
             eof = False
             while not eof:
                 units, eof = await pull()

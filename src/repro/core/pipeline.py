@@ -25,7 +25,6 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Iterator
 
-from ._compat import BaseExceptionGroup, TaskGroup
 from .engine import StageRuntime, StageSpec, StragglerPool
 from .errors import PipelineFailure, PipelineStopped
 from .queues import EOF, MonitoredQueue
@@ -174,7 +173,7 @@ class Pipeline:
         self._runtimes = runtimes
         self._sink_q = queues[-1]
         self._sink_ready.set()
-        async with TaskGroup() as tg:
+        async with asyncio.TaskGroup() as tg:
             for rt in runtimes:
                 tg.create_task(rt.run(), name=f"stage:{rt.spec.name}")
 

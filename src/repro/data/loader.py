@@ -100,17 +100,18 @@ The batch → device leg is chunked too, on both ends of the sink:
   hold window (``consumer_window + 1 + transfer_chunk``) and the arena's
   deadlock floor (see ``_ring_size``) grow with ``transfer_chunk``.
   Slabs still recycle per batch, in order, chunked or not.
-* **Failure**: the vectorized stage fails whole-chunk — a ``device_put``
-  error poisons its chunk-mates (they were dispatched by the same call).
-  Batches are few and transfers don't fail per-sample, so this trades an
-  irrelevant failure granularity for the hop amortization.
+* **Failure is fatal**: the transfer stage runs ``on_error="fail"``, so
+  a failed ``device_put`` or on-chip decode raises ``PipelineFailure``
+  out of the consumer's next ``get_item``/``get_items`` instead of
+  dropping the batch (a skipped transfer would turn a decode that cannot
+  run on the device into an empty epoch, or an endless wait on a stream).
 
 With ``device_decode=DeviceDecode(mean, std, ...)`` the loader ships
 **uint8 wire bytes end to end**: slab rows stay uint8 through collate and
-transfer, and the fused ``dequant_normalize_augment`` kernel (uint8→bf16
-dequant, per-channel normalize, flip/crop augment, one VMEM pass) runs
+transfer, and the fused ``dequant_normalize_augment`` decode (uint8→bf16
+dequant, per-channel normalize, flip/crop augment, one XLA fusion) runs
 on-chip right after ``device_put`` — zero host-side float math on pixels.
-See ``data/transfer.py`` and ``kernels/dequant_normalize.py``.
+See ``data/transfer.py`` and ``kernels/ops.py``.
 
 **Checkpoint skip bound under chunking**: samples accumulate inside
 in-flight chunks before they reach a delivered batch, so a sampler
@@ -244,13 +245,17 @@ def _pipe_transfer(
     hop (``transfer_many`` as a vectorized chunk stage) — one executor call
     issues the whole chunk's ``device_put`` (+ fused decode) dispatches in
     order.  The ``cache=transfer`` probe surfaces ``device_decode_ms`` /
-    ``device_decode_batches`` on the transfer stage's stats row."""
+    ``device_decode_batches`` on the transfer stage's stats row.  A failed
+    transfer is fatal (``on_error="fail"``): it never drops a batch."""
     if transfer_chunk > 1:
         return builder.pipe(
             transfer.transfer_many, concurrency=1, name="transfer",
             chunk=transfer_chunk, vectorized=True, cache=transfer,
+            on_error="fail",
         )
-    return builder.pipe(transfer, concurrency=1, name="transfer", cache=transfer)
+    return builder.pipe(
+        transfer, concurrency=1, name="transfer", cache=transfer, on_error="fail"
+    )
 
 
 #: how many samples of headroom the shard-prefetch wrapper keeps between

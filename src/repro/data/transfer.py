@@ -17,8 +17,8 @@ kernels/dequant_normalize.py).  Integer payloads pass through untouched.
 
 ``device_decode=DeviceDecode(mean, std, ...)`` finishes the decode ON the
 accelerator: right after ``device_put`` the transfer dispatches the fused
-``dequant_normalize_augment`` kernel (uint8→bf16 dequant, per-channel
-normalize, per-sample flip/crop augment, one VMEM pass, NCHW out), so the
+``dequant_normalize_augment`` (uint8→bf16 dequant, per-channel normalize,
+per-sample flip/crop augment, one XLA fusion, NCHW out), so the
 host-side path never touches a pixel float — augment draws are tiny int
 arrays from a seeded numpy generator.  Dispatch cost is counted in
 ``device_decode_ms`` (the kernel itself runs async on the device) and

@@ -18,6 +18,10 @@ on the VPU, and writes the transposed layout.
 
 TARGET: TPU; validated with ``interpret=True`` against the ``ref.py``
 composition (``dequant_normalize_ref`` / ``dequant_normalize_augment_ref``).
+Off the main path: the ``(1, H, W, 1)`` blocks over NHWC break the TPU
+rule that the last two block dimensions be multiples of (8, 128) or whole,
+so the TPU compiler refuses both kernels and ``ops`` dispatches the XLA
+composition under ``use_pallas="auto"``.
 """
 
 from __future__ import annotations
@@ -28,10 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 ships the params class as TPUCompilerParams; newer as
-# CompilerParams — alias so interpret-mode validation runs on either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _dequant_kernel(x_ref, mean_ref, std_ref, o_ref):
@@ -62,7 +62,7 @@ def dequant_normalize(
         ],
         out_specs=pl.BlockSpec((1, 1, h, w), lambda ni, ci: (ni, ci, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c, h, w), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -130,7 +130,7 @@ def dequant_normalize_augment(
         ],
         out_specs=pl.BlockSpec((1, 1, oh, ow), lambda ni, ci: (ni, ci, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c, oh, ow), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

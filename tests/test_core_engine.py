@@ -372,7 +372,7 @@ def test_ordered_pipe_fail_fast_with_full_task_queue_no_deadlock():
 
 
 def test_fail_fast_completion_order_infinite_source():
-    """Regression (Python 3.10 TaskGroup backport): a child failure must
+    """Regression (TaskGroup fail-fast): a child failure must
     interrupt a stage body that is still awaiting input — with an infinite
     source the error would otherwise never surface and teardown would hang."""
 

@@ -1,8 +1,8 @@
 """The hot path to the device: chunked sink drain + on-chip fused decode.
 
 Covers the consumer/device boundary end to end, CPU-only (no hypothesis,
-no TPU — the fused kernel runs in interpret mode so ``use_pallas="auto"``
-stays safe on CPU CI):
+no TPU — the Pallas decode runs only in interpret mode here, and
+``use_pallas="auto"`` takes the XLA composition on every backend):
 
 * ``Pipeline.get_items`` chunk semantics + the mixed ``get_item`` /
   ``get_items`` timeout-polling regression (lossless, EOF exactly once)
@@ -12,6 +12,8 @@ stays safe on CPU CI):
   across dtypes, odd spatial shapes, and interpret mode
 * ``DeviceTransfer.transfer_many`` + ``DeviceDecode`` dispatch, and the
   new counters surfacing through stats → format_stats → /metrics
+* a failing transfer is fatal to the loader, never a dropped batch
+* ``chip_smoke``'s main path at tiny widths, on one and four CPU devices
 """
 
 import time
@@ -234,6 +236,16 @@ def test_fused_kernel_auto_is_safe_on_cpu():
     assert out.shape == (1, 3, 4, 4)
 
 
+def test_pallas_true_off_tpu_raises_instead_of_interpreting():
+    x = _sample(np.uint8, (1, 4, 4, 3))
+    mean = jnp.asarray(MEAN, jnp.float32)
+    std = jnp.asarray(STD, jnp.float32)
+    with pytest.raises(ValueError, match="TPU"):
+        ops.dequant_normalize_augment(x, mean, std, use_pallas=True)
+    with pytest.raises(ValueError, match="TPU"):
+        ops.dequant_normalize(x, mean, std, use_pallas=True)
+
+
 def test_fused_kernel_clamps_crop_offsets_like_dynamic_slice():
     x = _sample(np.uint8, (2, 8, 8, 3))
     mean = jnp.asarray(MEAN, jnp.float32)
@@ -399,3 +411,102 @@ def test_image_loader_device_decode_end_to_end(tmp_path):
     row = next(s for s in snaps if s.name == "transfer")
     assert row.device_decode_batches == len(got)
     assert snaps[-1].sink_drained_chunks > 0
+
+
+def test_image_loader_transfer_failure_raises_instead_of_dropping(tmp_path):
+    """A decode that cannot be dispatched (here: the Pallas kernel asked for
+    off-TPU) must end iteration with an error: a skipped transfer stage
+    would yield an empty epoch, or block a stream forever."""
+    from repro.core import PipelineFailure
+    from repro.data import SyntheticImageDataset, build_image_loader
+
+    ds = SyntheticImageDataset.materialize(tmp_path, 8, hw=(8, 8), seed=0)
+    dd = DeviceDecode(mean=MEAN, std=STD, use_pallas=True)
+    for epochs in (1, None):
+        pipe = build_image_loader(
+            ds, batch_size=4, hw=(8, 8), epochs=epochs, device_decode=dd,
+        )
+        with pipe.auto_stop():
+            with pytest.raises(PipelineFailure) as ei:
+                pipe.get_items(2, timeout=60)
+        assert ei.value.stage == "transfer"
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke's main path at tiny widths (the script itself runs on a TPU only)
+# ---------------------------------------------------------------------------
+TINY_VIT = dict(d_model=32, depth=1, heads=2, mlp=64, n_classes=10)
+
+
+def test_chip_smoke_main_path_matches_numpy_reference():
+    import chip_smoke
+    from benchmarks import vit
+
+    r = chip_smoke.run_main_path(
+        vit.ViTConfig(**TINY_VIT), batch=4, hw=(64, 64), out_hw=(48, 48), steps=3,
+    )
+    assert len(r["losses"]) == 3 and np.all(np.isfinite(r["losses"]))
+    # the compared batch came after the slab ring went round several times
+    assert r["checked_batch"] >= chip_smoke.RECYCLES * r["num_slabs"]
+    assert r["decode_max_abs_err"] <= 2.0**-6
+
+
+def test_chip_smoke_reference_check_catches_wrong_draws(tmp_path):
+    """The numpy comparison is sharp: the right batch under the NEXT batch's
+    crop/flip draws must fail it."""
+    import chip_smoke
+    from repro.data import SyntheticImageDataset
+
+    hw, out_hw, n = (32, 32), (16, 16), 4
+    ds = SyntheticImageDataset.materialize(tmp_path, n, hw=hw, seed=0)
+    draws = chip_smoke.augment_draws(0, n, hw, out_hw)
+    (flip, crop), (flip2, crop2) = next(draws), next(draws)
+    dd = DeviceDecode(mean=MEAN, std=STD, out_hw=out_hw, flip=True, crop=True)
+    tr = DeviceTransfer(uint8_wire=True, device_decode=dd)
+    from repro.data.codec import decode_sample
+
+    batch = np.stack([decode_sample(ds.read_bytes(i)) for i in range(n)])
+    got = tr({"images": batch})["images"]
+    idx = np.arange(n)
+    chip_smoke.check_decoded(got, chip_smoke.decode_reference(ds, idx, flip, crop, out_hw))
+    with pytest.raises(AssertionError, match="numpy reference"):
+        chip_smoke.check_decoded(
+            got, chip_smoke.decode_reference(ds, idx, flip2, crop2, out_hw)
+        )
+
+
+def test_chip_smoke_four_device_path_on_virtual_cpus():
+    """The --four-chips phase (sharded loader, data-parallel step, shard-by-
+    shard loss) on four virtual CPU devices, in a child process: the device
+    count is fixed when a process first touches JAX."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    code = (
+        "import json, jax, numpy as np\n"
+        "from jax.sharding import Mesh\n"
+        "import chip_smoke\n"
+        "from benchmarks import vit\n"
+        "mesh = Mesh(np.array(jax.devices()[:4]), ('data',))\n"
+        f"r = chip_smoke.run_main_path(vit.ViTConfig(**{TINY_VIT!r}), batch=16,\n"
+        "    hw=(64, 64), out_hw=(48, 48), steps=2, mesh=mesh)\n"
+        "print(json.dumps(r))\n"
+    )
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+        PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]),
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    r = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert r["shard_loss_gap"] <= 2.0**-7
+    assert np.all(np.isfinite(r["losses"]))
