@@ -138,7 +138,9 @@ Three layers, cheapest first (see ``core.trace`` / ``core.metrics``):
   ``MetricsExporter`` serves everything as Prometheus text on
   ``/metrics``.
 * **Flight recorder**: ``core.trace.Tracer`` — per-thread ring buffers of
-  span/instant events, exported as Chrome Trace Event JSON.
+  span/instant events, exported as Chrome Trace Event JSON, on the
+  tracer's own clock or, joined by ``Tracer.anchor()``, on a
+  ``jax.profiler`` trace's clock beside the device's operations.
 
 Tracer lifecycle: construct a ``Tracer``, pass it to ``build(trace=...)``
 (engine + queue spans) and/or install it process-wide with
@@ -161,8 +163,13 @@ thread's track carries the ``queue`` category: ``get_wait q:X`` spans mean
 X's consumer is starved (upstream too slow), ``put_wait q:X`` means X is
 full (downstream too slow) — the same backpressure story as the counters,
 but time-resolved.  ``straggler`` instants mark detach/resolve pairs, and
-``shard``/``transfer`` spans (cache fetches, host→device copies) come from
-the data layer when a process-wide tracer is installed.
+``shard``/``transfer`` spans come from the data layer when a process-wide
+tracer is installed: cache fetches, ``h2d`` spans from each
+``device_put`` until the copy is resident on the device, and the on-chip
+decode's dispatch.  An installed tracer also gets a ``compile`` span per
+JAX compile.  Mapped onto the profiler's clock, these spans say what the
+program was doing in each stretch where the device sat idle: waiting on a
+queue, a copy, or a compile.
 """
 
 from __future__ import annotations

@@ -337,6 +337,11 @@ def stage_metrics_lines(
                   "Host-side dispatch seconds spent launching the fused "
                   "on-chip decode (the device work itself is async).",
                   s.device_decode_ms / 1e3, **lb)
+        if s.h2d_unresident_releases:
+            f.add(f"{p}_h2d_unresident_releases_total", "counter",
+                  "Slabs a traced DeviceTransfer released before their "
+                  "host-to-device copy was seen resident.",
+                  s.h2d_unresident_releases, **lb)
         if s.sink_drained_chunks:
             f.add(f"{p}_sink_drained_chunks_total", "counter",
                   "Chunks the consumer pulled via the chunked sink drain "

@@ -143,6 +143,7 @@ class StageStats:
             origin_bytes=int(cache.get("source_origin_bytes", 0)),
             device_decode_ms=float(cache.get("device_decode_ms", 0.0)),
             device_decode_batches=int(cache.get("device_decode_batches", 0)),
+            h2d_unresident_releases=int(cache.get("h2d_unresident_releases", 0)),
         )
 
 
@@ -212,6 +213,9 @@ class StageStatsSnapshot:
     sink_drained_chunks: int = 0
     device_decode_ms: float = 0.0
     device_decode_batches: int = 0
+    # slabs a traced DeviceTransfer handed back before their copy was seen
+    # resident on the device (0 with tracing off)
+    h2d_unresident_releases: int = 0
 
 
 def format_stats(snaps: list[StageStatsSnapshot], window=None) -> str:
@@ -277,6 +281,10 @@ def format_stats(snaps: list[StageStatsSnapshot], window=None) -> str:
             lines.append(
                 f"[{s.name}] device-decode: batches={s.device_decode_batches}"
                 f" dispatch_ms={s.device_decode_ms:.1f} avg_ms={avg:.2f}"
+            )
+        if s.h2d_unresident_releases:
+            lines.append(
+                f"[{s.name}] h2d: unresident_releases={s.h2d_unresident_releases}"
             )
         if s.sink_drained_chunks:
             items = s.num_out / s.sink_drained_chunks

@@ -26,14 +26,37 @@ Design constraints, in order:
    (first event) and on export.  Ring bounds make a forgotten tracer a
    bounded-memory annoyance, not a leak.
 
+The profiler's clock.  Events are stamped with ``time.monotonic()``; a
+``jax.profiler`` trace stamps its own events in nanoseconds from the start
+of its trace.  ``Tracer.anchor()`` joins the two: it opens a
+``jax.profiler.TraceAnnotation("repro.anchor")`` and records, as an
+``anchor`` instant, the monotonic reading taken inside it.  Pairing the
+i-th ``repro.anchor`` event of the profile with the i-th recorded anchor
+gives one offset (``Tracer.clock_offsets``) that ``on_profiler_clock`` adds
+to every event in the rings; ``events``/``export`` take it as
+``offset_ns``.  Two anchors, one as the profiler starts and one as it stops,
+bound the drift between the clocks.
+
+Two event sources exist only while an enabled tracer is installed:
+
+* ``h2d`` spans (category ``transfer``, args ``bytes``, ``batch``):
+  ``data.transfer.DeviceTransfer`` measures each host-to-device copy from
+  its ``jax.device_put`` call until the array is resident on the device,
+  seen by one watcher thread per transfer that blocks on each put array in
+  the order of the ``device_put`` calls.
+* ``compile`` spans (category ``compile``, args ``fun``, ``cached``): while
+  ``set_tracer``/``tracing()`` holds an enabled tracer, a ``jax.monitoring``
+  listener turns JAX's backend-compile event into a span ending when the
+  event fires, ``cached`` true where the persistent compilation cache
+  answered it.  Uninstalling unregisters the listener.
+
 Usage::
 
     tracer = Tracer()                      # or: with tracing() as tracer:
-    set_tracer(tracer)                     # data-layer subsystems see it
+    set_tracer(tracer)                     # data layer + compiles see it
     pipe = builder.build(trace=tracer)     # engine + queues see it
     ... run ...
     tracer.export("trace.json")            # open in ui.perfetto.dev
-    tracer.export_jsonl("events.jsonl")    # structured log, one event/line
 """
 
 from __future__ import annotations
@@ -53,7 +76,16 @@ __all__ = [
     "get_tracer",
     "set_tracer",
     "tracing",
+    "on_profiler_clock",
 ]
+
+#: name of the profiler annotation that ``Tracer.anchor`` opens
+ANCHOR = "repro.anchor"
+#: JAX's duration events for one compile (``jax._src.dispatch``
+#: ``BACKEND_COMPILE_EVENT``, which wraps the persistent-cache lookup) and
+#: for a persistent-cache hit inside it (``jax._src.compiler``)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class _NullSpan:
@@ -93,9 +125,6 @@ class NullTracer:
     def instant(self, name: str, cat: str = "", args: dict | None = None) -> None:
         pass
 
-    def counter(self, name: str, values: dict) -> None:
-        pass
-
     def events(self) -> list:
         return []
 
@@ -127,13 +156,20 @@ class _Span:
         return False
 
 
+def on_profiler_clock(t: float, offset_ns: float) -> float:
+    """A ``time.monotonic()`` reading ``t`` (seconds) as nanoseconds on a
+    profile's clock, given that profile's ``offset_ns``
+    (``Tracer.clock_offsets``)."""
+    return t * 1e9 + offset_ns
+
+
 class Tracer:
     """Flight recorder with one bounded event ring per thread.
 
     Events are 6-tuples ``(ph, name, cat, ts, dur, args)`` with ``ts``/
     ``dur`` in *seconds* on the monotonic clock (converted to Chrome's
     microseconds at export).  ``ph`` follows the Chrome Trace Event Format:
-    ``"X"`` complete span, ``"i"`` instant, ``"C"`` counter.
+    ``"X"`` complete span, ``"i"`` instant.
     """
 
     def __init__(self, capacity_per_thread: int = 65536):
@@ -172,10 +208,26 @@ class Tracer:
         if self.enabled:
             self._ring().append(("i", name, cat, time.monotonic(), 0.0, args))
 
-    def counter(self, name: str, values: dict) -> None:
-        """Record a counter sample (rendered as a stacked chart in Perfetto)."""
-        if self.enabled:
-            self._ring().append(("C", name, "counter", time.monotonic(), 0.0, dict(values)))
+    def anchor(self) -> float:
+        """Record a clock anchor: the ``time.monotonic()`` reading taken
+        inside a ``jax.profiler.TraceAnnotation("repro.anchor")``, kept as
+        an ``anchor`` instant.  Call it while the profiler records."""
+        import jax  # lazily: core imports without JAX
+
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            t = time.monotonic()
+        self._ring().append(("i", "anchor", "clock", t, 0.0, None))
+        return t
+
+    def clock_offsets(self, starts_ns: list[float]) -> list[float]:
+        """Offsets (ns) from this tracer's clock to a profile's, one per
+        anchor: the i-th ``repro.anchor`` event's ``start_ns`` in the
+        profile minus the i-th recorded anchor's reading."""
+        anchors = sorted(
+            ts for _, _, evs in self._snapshots()
+            for ph, name, cat, ts, _, _ in evs if (ph, name, cat) == ("i", "anchor", "clock")
+        )
+        return [s - a * 1e9 for s, a in zip(starts_ns, anchors)]
 
     def span(self, name: str, cat: str = "", args: dict | None = None):
         """``with tracer.span("fetch", "shard"): ...`` — measures its own
@@ -201,9 +253,10 @@ class Tracer:
             out.append((tid, tname, evs))
         return out
 
-    def events(self) -> list[dict]:
-        """All recorded events as Chrome Trace Event dicts, sorted by ts."""
-        epoch = self._epoch
+    def events(self, offset_ns: float | None = None) -> list[dict]:
+        """All recorded events as Chrome Trace Event dicts, sorted by ts:
+        microseconds from the tracer's creation, or, given a profile's
+        ``offset_ns`` (``clock_offsets``), on that profile's clock."""
         rows: list[dict] = []
         for tid, tname, evs in self._snapshots():
             for ph, name, cat, ts, dur, args in evs:
@@ -211,7 +264,11 @@ class Tracer:
                     "ph": ph,
                     "name": name,
                     "cat": cat or "repro",
-                    "ts": (ts - epoch) * 1e6,
+                    "ts": (
+                        (ts - self._epoch) * 1e6
+                        if offset_ns is None
+                        else on_profiler_clock(ts, offset_ns) * 1e-3
+                    ),
                     "pid": self.pid,
                     "tid": tid,
                 }
@@ -236,9 +293,10 @@ class Tracer:
         return sum(len(evs) for _, _, evs in self._snapshots())
 
     # -- export -----------------------------------------------------------
-    def to_chrome(self) -> dict:
+    def to_chrome(self, offset_ns: float | None = None) -> dict:
         """The trace as a Chrome Trace Event Format object: metadata events
-        naming each thread track, then the data events."""
+        naming each thread track, then the data events (on a profile's clock
+        given its ``offset_ns``)."""
         meta: list[dict] = [
             {
                 "ph": "M",
@@ -258,23 +316,12 @@ class Tracer:
                     "args": {"name": tname},
                 }
             )
-        return {"traceEvents": meta + self.events(), "displayTimeUnit": "ms"}
+        return {"traceEvents": meta + self.events(offset_ns), "displayTimeUnit": "ms"}
 
-    def export(self, path: str) -> str:
+    def export(self, path: str, offset_ns: float | None = None) -> str:
         """Write Chrome Trace Event JSON; open in ui.perfetto.dev."""
         with open(path, "w") as f:
-            json.dump(self.to_chrome(), f, default=repr)
-        return path
-
-    def export_jsonl(self, path: str) -> str:
-        """Structured event log: one JSON object per line (for grep/jq and
-        log shippers — same events, no Chrome framing)."""
-        by_tid = {tid: tname for tid, tname, _ in self._snapshots()}
-        with open(path, "w") as f:
-            for ev in self.events():
-                row = dict(ev)
-                row["thread"] = by_tid.get(ev["tid"], "")
-                f.write(json.dumps(row, default=repr) + "\n")
+            json.dump(self.to_chrome(offset_ns), f, default=repr)
         return path
 
 
@@ -293,12 +340,51 @@ def get_tracer() -> Tracer | NullTracer:
     return _active
 
 
+_compile_listener: "_CompileListener | None" = None
+
+
+class _CompileListener:
+    """``jax.monitoring`` duration listener: each backend compile becomes a
+    ``compile`` span ``[now - duration, now]`` on the compiling thread.  A
+    persistent-cache hit fires its retrieval event inside the compile event
+    on the same thread, which marks that compile ``cached``."""
+
+    def __init__(self, tracer: Tracer):
+        self.tracer = tracer
+        self._local = threading.local()
+
+    def __call__(self, event: str, duration_secs: float, **kwargs) -> None:
+        if event == CACHE_RETRIEVAL_EVENT:
+            self._local.cached = True
+        elif event == BACKEND_COMPILE_EVENT:
+            cached = getattr(self._local, "cached", False)
+            self._local.cached = False
+            now = time.monotonic()
+            self.tracer.complete(
+                "compile", "compile", now - duration_secs, duration_secs,
+                {"fun": kwargs.get("fun_name", ""), "cached": cached},
+            )
+
+
 def set_tracer(tracer: Tracer | NullTracer | None) -> Tracer | NullTracer:
     """Install ``tracer`` process-wide; returns the previous one.
-    ``None`` uninstalls (restores the no-op)."""
-    global _active
+    ``None`` uninstalls (restores the no-op).  An enabled tracer also
+    receives a ``compile`` span for each JAX compile while installed."""
+    global _active, _compile_listener
     prev = _active
     _active = tracer if tracer is not None else NULL_TRACER
+    if _compile_listener is not None:
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(_compile_listener)
+        _compile_listener = None
+    if _active.enabled:
+        try:
+            from jax import monitoring
+        except ImportError:  # core runs without JAX: nothing compiles
+            return prev
+        _compile_listener = _CompileListener(_active)
+        monitoring.register_event_duration_secs_listener(_compile_listener)
     return prev
 
 

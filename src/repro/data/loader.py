@@ -484,11 +484,13 @@ def build_image_loader(
         builder = builder.aggregate(
             batch_size, drop_last=True, name="batch"
         ).pipe(make_batch, name="collate")
-        return (
+        pipe = (
             _pipe_transfer(builder, transfer, transfer_chunk)
             .add_sink(buffer_size=sink_buffer)
             .build(num_threads=num_threads, trace=trace)
         )
+        pipe.add_stop_callback(transfer.flush)
+        return pipe
 
     # Zero-copy slab path (see module docstring "Memory model").
     arena = SlabArena(
@@ -636,6 +638,7 @@ def build_lm_loader(
             .add_sink(buffer_size=sink_buffer)
             .build(num_threads=num_threads, trace=trace)
         )
+        pipe.add_stop_callback(transfer.flush)
         return pipe, sampler
 
     row_shape = ((seq_len,), np.int32)

@@ -50,11 +50,25 @@ reliably once up front.  Holding the full window is a few batch-buffers of
 host memory; releasing early is silent data corruption.  Consumers that
 retain batches beyond the current iteration must copy them.  No
 ``block_until_ready()`` ever enters the hot path.
+
+Tracing (only while the transfer's tracer is enabled, see ``core.trace``):
+each copy is one ``h2d`` span from its ``device_put`` call until the put
+array is resident.  Residency is seen by one watcher thread per transfer,
+which blocks on each put array in ``device_put`` order (the array before
+the on-chip decode, which does not donate it), off the hot path; the
+thread starts on the first traced call and ``flush()`` stops it.
+``h2d_unresident_releases`` counts the slabs the hold ring released
+before the watcher had seen their copy resident, on the transfer stage's
+stats row via ``stats()``.  With tracing off no thread starts and the
+counter stays 0.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import logging
+import queue
+import threading
 import time
 from collections import deque
 from typing import Any
@@ -65,6 +79,8 @@ import numpy as np
 
 from ..core import trace as _trace
 from .arena import SLAB_KEY
+
+logger = logging.getLogger("repro.data")
 
 #: absolute slack allowed past [0, 1] before a float wire payload is
 #: rejected — covers resize/antialias ringing, not wrong normalization
@@ -123,6 +139,37 @@ class DeviceDecode:
     use_pallas: Any = "auto"  # "auto" | True | "interpret" | False
 
 
+class _ResidencyWatcher:
+    """One thread that sees each traced copy resident, in put order: it
+    blocks on each put array and records the copy's ``h2d`` span."""
+
+    def __init__(self):
+        self.resident = 0  # highest batch number seen resident
+        self._pending: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(target=self._run, name="h2d-watcher", daemon=True)
+        self._thread.start()
+
+    def watch(self, tracer, put: Any, t0: float, nbytes: int, batch: int) -> None:
+        self._pending.put((tracer, put, t0, nbytes, batch))
+
+    def _run(self) -> None:
+        while (item := self._pending.get()) is not None:
+            tracer, put, t0, nbytes, batch = item
+            try:
+                jax.block_until_ready(put)
+            except Exception:  # the consumer sees the failure; keep watching
+                logger.warning("h2d watcher: batch %d failed its copy", batch, exc_info=True)
+                continue
+            t1 = time.monotonic()
+            tracer.complete("h2d", "transfer", t0, t1 - t0, {"bytes": nbytes, "batch": batch})
+            self.resident = batch
+
+    def stop(self) -> None:
+        """Wait for every watched copy, then end the thread."""
+        self._pending.put(None)
+        self._thread.join()
+
+
 class DeviceTransfer:
     def __init__(
         self,
@@ -150,10 +197,13 @@ class DeviceTransfer:
         # the kernel runs async); surfaced via stats() → the stage probe
         self.device_decode_ms = 0.0
         self.device_decode_batches = 0
+        self.h2d_unresident_releases = 0
         # explicit tracer, else whatever is installed process-wide at call
-        # time (host→device spans land on the worker thread's track)
+        # time (host→device spans land on the watcher thread's track)
         self._tracer = tracer
-        self._held: deque[Any] = deque()
+        self._watcher: _ResidencyWatcher | None = None
+        # (slab, batch number whose copy reads it; 0 = copy not watched)
+        self._held: deque[tuple[Any, int]] = deque()
         if device_decode is not None:
             self._decode_mean = jnp.asarray(device_decode.mean, jnp.float32)
             self._decode_std = jnp.asarray(device_decode.std, jnp.float32)
@@ -173,26 +223,31 @@ class DeviceTransfer:
         self.bytes_moved += nbytes
         self.num_batches += 1
         tracer = self._tracer if self._tracer is not None else _trace.get_tracer()
-        t0 = time.monotonic() if tracer.enabled else 0.0
+        watched = self.num_batches if tracer.enabled else 0
+        t0 = time.monotonic() if watched else 0.0
         if self.shardings is None:
             out = jax.device_put(batch)
         else:
             out = jax.device_put(batch, self.shardings)
-        if tracer.enabled:
-            # dispatch time only: device_put is async, so this span is the
-            # host-side cost; the wire time overlaps the consumer's step
-            tracer.complete(
-                "device_put", "transfer", t0, time.monotonic() - t0,
-                {"bytes": nbytes, "batch": self.num_batches},
-            )
+        if watched:
+            watcher = self._watcher
+            if watcher is None:
+                watcher = self._watcher = _ResidencyWatcher()
+            watcher.watch(tracer, out, t0, nbytes, watched)
         out = self._maybe_decode(out, tracer)
         if slab is not None:
             # The copy for `slab` is now in flight; recycle the one from
             # hold_slabs batches ago, whose copy is certainly consumed.
-            self._held.append(slab)
+            self._held.append((slab, watched))
             while len(self._held) > self.hold_slabs:
-                self._held.popleft().release()
+                self._release(*self._held.popleft())
         return out
+
+    def _release(self, slab: Any, batch: int) -> None:
+        watcher = self._watcher
+        if watcher is not None and batch > watcher.resident:
+            self.h2d_unresident_releases += 1
+        slab.release()
 
     def transfer_many(self, batches: list) -> list:
         """Vectorized-chunk entry point: dispatch a drained chunk of batches
@@ -258,10 +313,16 @@ class DeviceTransfer:
         return {
             "device_decode_ms": self.device_decode_ms,
             "device_decode_batches": self.device_decode_batches,
+            "h2d_unresident_releases": self.h2d_unresident_releases,
         }
 
     def flush(self) -> None:
-        """Release every held slab (end of stream / teardown).  Callers must
-        ensure pending transfers are consumed (e.g. the pipeline drained)."""
+        """Stop the residency watcher once every watched copy is resident,
+        then release every held slab (end of stream / teardown).  Callers
+        must ensure pending transfers are consumed (e.g. the pipeline
+        drained)."""
+        watcher, self._watcher = self._watcher, None
+        if watcher is not None:
+            watcher.stop()
         while self._held:
-            self._held.popleft().release()
+            self._held.popleft()[0].release()
