@@ -85,9 +85,11 @@ def encode_sample(arr: np.ndarray) -> bytes:
 
 
 def decode_sample(data: bytes) -> np.ndarray:
-    """GIL-releasing decode (zstd/zlib C ext + numpy frombuffer)."""
+    """GIL-releasing decode (zstd/zlib C ext + numpy frombuffer).  The
+    payload is a ``memoryview`` slice: slicing ``bytes`` would copy it,
+    holding the GIL, into freshly allocated memory."""
     dtype, shape, off = parse_header(data)
-    payload = _decompress(data[off:])
+    payload = _decompress(memoryview(data)[off:])
     return np.frombuffer(payload, dtype=dtype).reshape(shape)
 
 
@@ -105,7 +107,7 @@ def decode_into(data: bytes, out: np.ndarray) -> np.ndarray:
         )
     if not out.flags["C_CONTIGUOUS"]:
         raise ValueError("decode_into requires a C-contiguous out buffer")
-    payload = data[off:]
+    payload = memoryview(data)[off:]  # no copy, as in decode_sample
     if zstandard is not None and payload[:4] == _ZSTD_FRAME_MAGIC:
         view = memoryview(out).cast("B")
         need = out.nbytes

@@ -11,6 +11,11 @@ with segment ids → slab batch assembly → shard-aware device placement.
 Every stage's concurrency is tunable (paper "Tunability"); stats from
 ``Pipeline.stats()`` expose the bottleneck stage (paper "Visibility") and,
 for the slab path, memory pressure (``slabs_in_flight``/``bytes_allocated``).
+The image loader's default of 4 read+decode workers is measured, not
+guessed: on a 13-CPU TPU host, 4 to 12 workers deliver the same images per
+second because the record-file reads queue behind one another, and every
+worker past 4 only adds CPU per image (PERF.md §6).  Raise the width where
+reads scale.
 
 Memory model (zero-copy slab path, default ``zero_copy=True``)
 ---------------------------------------------------------------

@@ -19,7 +19,7 @@ from repro.data import (
     encode_sample,
 )
 from repro.data.arena import SLAB_KEY
-from repro.data.codec import decode_into, resize_nearest, resize_nearest_into
+from repro.data.codec import decode_into, parse_header, resize_nearest, resize_nearest_into
 from repro.data.packing import SequencePacker
 from repro.data.transfer import DeviceTransfer
 
@@ -80,6 +80,27 @@ def test_decode_into_matches_decode_sample():
         decode_into(data, np.empty((8, 12, 3), np.uint8))  # shape mismatch
     with pytest.raises(ValueError):
         decode_into(b"XXXX" + data[4:], out)  # corrupt
+
+
+@pytest.mark.parametrize("container", [bytes, bytearray, memoryview])
+@pytest.mark.parametrize("compressor", ["zstd", "zlib"])
+def test_decode_reads_payload_in_place(compressor, container):
+    """Both decoders read a record from any buffer and either codec, and
+    what they return does not alias the record's memory."""
+    img = np.random.default_rng(1).integers(0, 256, (9, 14, 3), dtype=np.uint8)
+    data = encode_sample(img)
+    if compressor == "zlib":
+        import zlib
+
+        off = parse_header(data)[2]
+        data = data[:off] + zlib.compress(img.tobytes(), 1)
+    buf = bytearray(data)
+    got = decode_sample(container(buf))
+    out = np.empty_like(img)
+    decode_into(container(buf), out)
+    buf[len(buf) // 2:] = bytes(len(buf) - len(buf) // 2)  # scribble the record
+    np.testing.assert_array_equal(got, img)
+    np.testing.assert_array_equal(out, img)
 
 
 def test_resize_nearest_into_matches_resize_nearest():
