@@ -11,7 +11,8 @@ bilinear field with a base colour, the low bits cleared, plus noise taken
 from a per-run pool.  ``record_pixels`` is what the reference decode reads
 as the truth; ``write_corpus`` encodes the same pixels with the program's
 codec into an ``ArrayDataset`` directory (one file per record, an
-``index.txt``), in parallel threads, one record at a time.
+``index.txt``), in parallel threads, one record at a time;
+``encode_records`` encodes them alike into memory.
 """
 
 from __future__ import annotations
@@ -92,19 +93,26 @@ def record_pixels(traffic: dict, seed: int, i: int, hw, pool: np.ndarray) -> np.
     return img
 
 
+def encoder(traffic: dict, n: int, seed: int):
+    """(sizes, ``encode(i)``): the records' (n, 2) sizes, and record ``i``
+    encoded with the program's codec."""
+    from repro.data.codec import encode_sample
+
+    sizes = record_sizes(traffic, n, seed)
+    pool = noise_pool(traffic, seed)
+    return sizes, lambda i: encode_sample(record_pixels(traffic, seed, i, sizes[i], pool))
+
+
 def write_corpus(root, traffic: dict, n: int, seed: int, threads: int) -> dict:
     """Write ``n`` records and ``index.txt`` under ``root``; returns sizes
     and the raw and encoded byte counts."""
-    from repro.data.codec import encode_sample
-
     root = pathlib.Path(root)
     root.mkdir(parents=True, exist_ok=True)
-    sizes = record_sizes(traffic, n, seed)
-    pool = noise_pool(traffic, seed)
+    sizes, encode = encoder(traffic, n, seed)
     names = [f"{i:06d}.rpr" for i in range(n)]
 
     def one(i: int) -> int:
-        data = encode_sample(record_pixels(traffic, seed, i, sizes[i], pool))
+        data = encode(i)
         (root / names[i]).write_bytes(data)
         return len(data)
 
@@ -113,3 +121,10 @@ def write_corpus(root, traffic: dict, n: int, seed: int, threads: int) -> dict:
     (root / "index.txt").write_text("\n".join(names))
     raw = int((sizes[:, 0] * sizes[:, 1]).sum() * 3)
     return {"sizes": sizes, "raw_bytes": raw, "encoded_bytes": int(encoded)}
+
+
+def encode_records(traffic: dict, n: int, seed: int, threads: int) -> list[bytes]:
+    """The ``n`` records ``write_corpus`` would write, encoded in memory."""
+    _, encode = encoder(traffic, n, seed)
+    with cf.ThreadPoolExecutor(max_workers=threads) as ex:
+        return list(ex.map(encode, range(n)))

@@ -3,14 +3,30 @@
 Everything that belongs to one cell is found by name: the cell in
 ``BENCHMARK.json`` names its configuration (``bench/configs/<name>.json``)
 and traffic (``bench/traffic/<name>.json``); the configuration names its
-consumer (``bench/consumers/<name>.py``) and, where it has one, its plain
-reference beside it; each metric is read by ``bench/metrics/<name>.py``.
+consumer (``bench/consumers/<name>.py``), its feed
+(``bench/feeds/<name>.py``, ``image_files`` where it names none) and,
+where it has one, its plain reference beside it; each metric is read by
+``bench/metrics/<name>.py``.
+
+A feed owns what is particular to the records and their storage:
+
+- ``fields``: the batch keys the consumer gets; with one key the consumer
+  gets that array, with several a dict of them;
+- ``write(workdir, config, traffic, n, seed, threads)`` lays the corpus
+  out from the seed;
+- ``build(workdir, config, traffic, *, batch, seed, shardings, sampler)``
+  returns the pipeline as a user states it (``get_item``, ``stats``,
+  ``queue_depths``, ``auto_stop``);
+- ``reference_batches(config, traffic, *, n, batch, seed, shuffle)`` returns
+  ``batch_at(k, dtype=np.float32)``, the plain reference of the stream's
+  ``k``-th batch in the consumer's form;
+- ``check(sample, batch_at, calibrate)`` returns the numbers compared with
+  the configuration's ``limits`` and, with ``calibrate``, the control's.
 
 Set-up: JAX and the chips, the corpus written from the seed into a
-temporary directory, the consumer's state made on the device, the loader
-built as a user states it (``build_image_loader`` with every tunable at
-the program's default), the consumer compiled on the first batch and
-driven through its first steps, then the sink left to fill.
+temporary directory, the consumer's state made on the device, the feed's
+pipeline built, the consumer compiled on the first batch and driven
+through its first steps, then the sink left to fill.
 
 The window: at most two steps in flight (after dispatching step ``k`` the
 loop blocks on step ``k - 1``), batches taken from the loader one at a
@@ -20,9 +36,9 @@ last ``SETTLE_S + TRACE_S`` seconds of it, and the reduction reads the
 last ``TRACE_S``: starting the profiler stalls host-to-device copies.
 
 The check, once the window has closed and the device's peak memory has
-been read: a seeded sample of the window's decoded batches against the
-reference decode, each chip's rows against its place, the samples the
-loader failed, and whatever the consumer compares.
+been read: a seeded sample of the window's batches against the feed's
+reference, each chip's rows of every field against its place, the
+samples the loader failed, and whatever the consumer compares.
 """
 
 from __future__ import annotations
@@ -41,12 +57,11 @@ from collections import deque
 
 import numpy as np
 
-from bench import corpus, reference
-
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 TRACE_S = 4.0  # seconds of the window a traced run reads from the profile
 SETTLE_S = 3.0  # the profiler stalls transfers as it starts: record, but skip, this much
 SINK_FILL_S = 5.0  # longest wait for the sink to fill before the window
+DEFAULT_FEED = "image_files"
 
 
 class NoChip(RuntimeError):
@@ -74,12 +89,13 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> dict:
     configs = {c["name"]: c for c in spec["configs"]}
     config = json.loads((root / configs[cell["config"]]["file"]).read_text())
     traffic = json.loads((root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
+    feed = root / "bench" / "feeds" / f"{config.get('feed', DEFAULT_FEED)}.py"
     e2e = [m for m in spec["end_to_end"] if _covers(m, workload)]
     moved = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"] if _covers(m, workload) and m["moves"] in moved]
     return {
         "cell": cell, "config": config, "config_dir": (root / configs[cell["config"]]["file"]).parent,
-        "traffic": traffic, "end_to_end": e2e, "per_layer": layer,
+        "traffic": traffic, "feed": feed, "end_to_end": e2e, "per_layer": layer,
     }
 
 
@@ -127,7 +143,8 @@ def stage_delta(before: dict, after: dict) -> dict:
 
 class Feed:
     """The consumer's side of the loader: batches in stream order, and the
-    stream index of each.
+    stream index of each.  A batch is the pipeline's item cut to
+    ``fields``: that field's array where there is one, else a dict.
 
     The loader hands a batch's host memory back to its ring a fixed number
     of batches after the batch's copy to the device is issued, not once
@@ -136,26 +153,32 @@ class Feed:
     copy that the runtime holds back (as the profiler's start does) reads
     memory already refilled with later records."""
 
-    def __init__(self, pipe):
-        self.pipe, self.stash, self.taken, self.last = pipe, deque(), 0, None
+    def __init__(self, pipe, fields: tuple[str, ...]):
+        self.pipe, self.fields = pipe, tuple(fields)
+        self.stash, self.taken, self.last = deque(), 0, None
+
+    def pick(self, item):
+        if len(self.fields) == 1:
+            return item[self.fields[0]]
+        return {f: item[f] for f in self.fields}
 
     def next(self):
         import jax
 
         if self.last is not None:
             with jax.profiler.TraceAnnotation("bench.batch_ready"):
-                self.last.block_until_ready()
+                jax.block_until_ready(self.last)
         with jax.profiler.TraceAnnotation("bench.get_batch"):
             if not self.stash:
-                self.stash.append(self.pipe.get_item())
+                self.stash.append(self.pick(self.pipe.get_item()))
             self.taken += 1
-            self.last = self.stash.popleft()["images"]
+            self.last = self.stash.popleft()
             return self.last
 
     def peek(self):
         x = self.next()
         self.taken -= 1
-        self.stash.appendleft({"images": x})
+        self.stash.appendleft(x)
         return x
 
 
@@ -164,7 +187,7 @@ class Reservoir:
 
     def __init__(self, k: int, seed: int):
         self.k, self.seen, self.items = k, 0, []
-        self.rng = np.random.default_rng(corpus.seed_words(seed, 5))
+        self.rng = np.random.default_rng([seed, 5])
 
     def offer(self, index: int, x) -> None:
         if len(self.items) < self.k:
@@ -176,9 +199,15 @@ class Reservoir:
         self.seen += 1
 
 
-def misplaced_rows(x, devices) -> int:
-    """Rows that are not on the chip that owns them (chip ``i`` of ``n``
-    owns the ``i``-th block of rows)."""
+def misplaced_rows(batch, devices) -> int:
+    """Rows of any field that are not on the chip that owns them (chip
+    ``i`` of ``n`` owns the ``i``-th block of rows)."""
+    import jax
+
+    return max(_misplaced(x, devices) for x in jax.tree_util.tree_leaves(batch))
+
+
+def _misplaced(x, devices) -> int:
     rows = x.shape[0] // len(devices)
     bad, starts = 0, set()
     for s in x.addressable_shards:
@@ -191,8 +220,9 @@ def misplaced_rows(x, devices) -> int:
 
 @dataclasses.dataclass
 class CheckContext:
-    """What a consumer's check may use: the seed, the mesh, the reference
-    decode of any batch of the stream, and the configuration's reference."""
+    """What a consumer's check may use: the seed, the mesh, the feed's
+    reference of any batch of the stream, and the configuration's
+    reference."""
 
     seed: int
     mesh: object
@@ -214,9 +244,7 @@ def run_cell(
 
     chips = chips_for(int(cell["chips"]), platform)
     from repro.compile_cache import use_compile_cache
-    from repro.data import ArrayDataset, build_image_loader
     from repro.data.sampler import CheckpointableSampler
-    from repro.data.transfer import DeviceDecode
 
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -226,29 +254,23 @@ def run_cell(
     n = int(config["num_records"])
     if n % batch:
         raise ValueError(f"num_records {n} is not a whole number of batches of {batch}")
-    lcfg = config["loader"]
     shuffle = bool(traffic["sampler"]["shuffle"])
-    hw, out_hw = tuple(lcfg["hw"]), tuple(lcfg["out_hw"])
     bench_dir = root / "bench"
     consumer_mod = load_module(bench_dir / "consumers" / f"{config['consumer']}.py",
                                f"bench_consumer_{config['consumer']}")
+    feed_mod = load_module(spec["feed"], f"bench_feed_{spec['feed'].stem}")
     workdir = tempfile.mkdtemp(prefix="bench-corpus-")
     try:
         parts = {"start_s": time.monotonic() - t0}
-        corpus.write_corpus(workdir, traffic, n, seed, threads=min(16, os.cpu_count() or 4))
+        feed_mod.write(workdir, config, traffic, n, seed, threads=min(16, os.cpu_count() or 4))
         parts["corpus_s"] = time.monotonic() - t0 - sum(parts.values())
         consumer = consumer_mod.Consumer(config, mesh, seed, batch)
-        pipe = build_image_loader(
-            ArrayDataset(workdir), batch_size=batch, hw=hw, uint8_wire=True,
-            device_decode=DeviceDecode(
-                tuple(lcfg["mean"]), tuple(lcfg["std"]), out_hw=out_hw,
-                crop=bool(lcfg["crop"]), flip=bool(lcfg["flip"]), seed=seed,
-            ),
-            epochs=None, shardings=rows,
+        pipe = feed_mod.build(
+            workdir, config, traffic, batch=batch, seed=seed, shardings=rows,
             sampler=CheckpointableSampler(n, batch_size=batch, seed=seed, shuffle=shuffle),
         )
         with pipe.auto_stop():
-            feed = Feed(pipe)
+            feed = Feed(pipe, feed_mod.fields)
             x0 = feed.peek()
             parts["first_batch_s"] = time.monotonic() - t0 - sum(parts.values())
             consumer.compile(x0)
@@ -265,26 +287,14 @@ def run_cell(
             w["setup_s"] = w["t_start"] - t0
         memory_peak = max(device_peak_bytes(d.memory_stats() or {}) for d in chips)
         misplaced = max((misplaced_rows(x, chips) for _, x in w["sample"]), default=0)
-        sample = [(k, np.asarray(jax.device_get(x))) for k, x in w.pop("sample")]
+        sample = [(k, jax.tree_util.tree_map(np.asarray, jax.device_get(x)))
+                  for k, x in w.pop("sample")]
         consumer.free()
 
-        truth = reference.Truth(traffic, n, seed)
-        ref_batch = lambda k, dtype=np.float32: reference.decode_batch(
-            truth, k, n=n, batch=batch, seed=seed, hw=hw, out_hw=out_hw,
-            mean=lcfg["mean"], std=lcfg["std"], shuffle=shuffle, dtype=dtype,
-        )
-        numbers = {
-            "decode_err_ulp": max(reference.bf16_ulps(x, ref_batch(k)) for k, x in sample),
-            "misplaced_rows": float(misplaced),
-            "failed_samples": float(w["failed"]),
-        }
-        readings: dict = {}
-        if calibrate:
-            import ml_dtypes
-
-            readings["control"] = {"decode_err_ulp": max(
-                reference.bf16_ulps(ref_batch(k, ml_dtypes.bfloat16), ref_batch(k))
-                for k, _ in sample)}
+        ref_batch = feed_mod.reference_batches(config, traffic, n=n, batch=batch, seed=seed,
+                                       shuffle=shuffle)
+        numbers, readings = feed_mod.check(sample, ref_batch, calibrate)
+        numbers.update(misplaced_rows=float(misplaced), failed_samples=float(w["failed"]))
         ctx = CheckContext(
             seed=seed, mesh=mesh, calibrate=calibrate, reference_batch=ref_batch,
             reference_module=lambda: load_module(

@@ -31,6 +31,12 @@ def test_same_seed_same_files(tmp_path):
     assert a["encoded_bytes"] == b["encoded_bytes"] != c["encoded_bytes"]
 
 
+def test_records_in_memory_are_the_files(tmp_path):
+    corpus.write_corpus(tmp_path, TRAFFIC, 16, 3_000_000_001, threads=3)
+    blobs = corpus.encode_records(TRAFFIC, 16, 3_000_000_001, threads=2)
+    assert blobs == [(tmp_path / f"{i:06d}.rpr").read_bytes() for i in range(16)]
+
+
 @pytest.mark.parametrize("n", [100, 2048])
 def test_every_seed_gets_the_size_table(n):
     rows = TRAFFIC["record_sizes"]["rows"]

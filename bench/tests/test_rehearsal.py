@@ -76,11 +76,66 @@ def test_vit_one_device_is_correct(root):
 
 
 def test_control_fails_the_limits(root):
-    for workload in ("it.1", "vit.1"):
+    for workload in ("it.1", "vit.1", "native.1", "shards.1"):
         r = run(root, workload, calibrate=True)
         limits = {k: c["limit"] for k, c in r["checks"].items()}
         control = r["readings"]["control"]
         assert any(v > limits[k] for k, v in control.items()), (workload, control, limits)
+
+
+@pytest.mark.parametrize("workload", ["native.1", "shards.1"])
+def test_stored_and_sharded_records_are_correct(root, workload):
+    r = run(root, workload)
+    assert list(r) == CONTRACT and r["correct"], r["checks"]
+    assert r["failed"] == 0 and r["checks"]["decode_err_ulp"]["value"] == 0.0
+    assert set(r["metrics"]) == {"samples_per_s", "step_p95_ms", "host_cpu_ms_per_img", "setup_s"}
+
+
+def test_shards_hold_the_records_and_no_files(root, tmp_path):
+    from repro.data.shards import ShardDataset
+
+    spec = harness.load_cell("shards.1", root)
+    feed = harness.load_module(spec["feed"], "feed_under_test")
+    feed.write(tmp_path, spec["config"], spec["traffic"], 64, SEED, threads=2)
+    assert sorted(p.suffix for p in tmp_path.iterdir()) == [".json", ".rpshard"]
+    from bench import corpus
+
+    blobs = corpus.encode_records(spec["traffic"], 64, SEED, threads=2)
+    ds = ShardDataset(tmp_path)
+    try:
+        assert [bytes(ds.read_fields(i, ("image",))["image"]) for i in range(64)] == blobs
+    finally:
+        ds.close()
+
+
+def test_a_corrupt_record_in_a_shard_is_not_correct(root, monkeypatch):
+    """One record's bytes altered in its shard after packing: the loader's
+    read fails it, and the run reads false."""
+    real = harness.load_module
+
+    def load(path, name):
+        mod = real(path, name)
+        if name == "bench_feed_image_shards":
+            write = mod.write
+
+            def corrupt(workdir, config, traffic, n, seed, threads):
+                write(workdir, config, traffic, n, seed, threads)
+                from bench import corpus
+
+                blob = corpus.encode_records(traffic, n, seed, threads)[5]
+                shard = next(pathlib.Path(workdir).glob("*.rpshard"))
+                data = bytearray(shard.read_bytes())
+                at = data.find(blob) + len(blob) // 2
+                data[at] ^= 0xFF
+                shard.write_bytes(bytes(data))
+
+            mod.write = corrupt
+        return mod
+
+    monkeypatch.setattr(harness, "load_module", load)
+    r = run(root, "shards.1")
+    assert not r["correct"], r["checks"]
+    assert r["checks"]["failed_samples"]["value"] > 0
 
 
 def _alter_decode(monkeypatch, how):
@@ -94,7 +149,7 @@ def _alter_decode(monkeypatch, how):
     monkeypatch.setattr(ops, "dequant_normalize_augment", broken)
 
 
-@pytest.mark.parametrize("workload", ["it.1", "vit.1"])
+@pytest.mark.parametrize("workload", ["it.1", "vit.1", "native.1", "shards.1"])
 @pytest.mark.parametrize("fault", ["answer_altered", "half_batch_repeated"])
 def test_broken_decode_is_not_correct(root, monkeypatch, workload, fault):
     how = {
@@ -214,7 +269,7 @@ def test_feed_waits_for_the_batch_in_hand_before_the_next():
             events.append(("get", self.n))
             return {"images": Batch(self.n)}
 
-    feed = harness.Feed(Pipe())
+    feed = harness.Feed(Pipe(), ("images",))
     assert feed.peek().i == 1 and feed.next().i == 1
     assert [feed.next().i, feed.next().i] == [2, 3]
     assert events == [("get", 1), ("ready", 1), ("ready", 1), ("get", 2), ("ready", 2), ("get", 3)]
@@ -249,3 +304,145 @@ def test_a_cell_is_added_from_files_alone(root, tmp_path):
     assert r["metrics"]["batches_per_s"]["value"] == pytest.approx(
         r["attempted"] / 16 / (r["attempted"] / 16 / r["metrics"]["batches_per_s"]["value"]))
     assert r["attempted"] % 16 == 0
+
+
+_PAIR_FEED = '''
+"""Images and an int32 token field in one batch: the image loader's batch
+with each batch's tokens placed beside it, on the same rows."""
+import numpy as np
+
+from bench.feeds import image_files
+
+fields = ("images", "tokens")
+SEQ = 16
+write = image_files.write
+
+
+def tokens_of(k, batch):
+    return (np.arange(batch * SEQ, dtype=np.int32).reshape(batch, SEQ) * 7 + k) % 1000
+
+
+class Pair:
+    def __init__(self, pipe, shardings, batch):
+        self.pipe, self.shardings, self.batch, self.k = pipe, shardings, batch, 0
+
+    def get_item(self):
+        import jax
+
+        item = dict(self.pipe.get_item())
+        item["tokens"] = jax.device_put(tokens_of(self.k, self.batch), self.shardings)
+        self.k += 1
+        return item
+
+    def stats(self):
+        return self.pipe.stats()
+
+    def queue_depths(self):
+        return self.pipe.queue_depths()
+
+    def auto_stop(self):
+        return self.pipe.auto_stop()
+
+
+def build(workdir, config, traffic, *, batch, seed, shardings, sampler):
+    pipe = image_files.build(workdir, config, traffic, batch=batch, seed=seed,
+                             shardings=shardings, sampler=sampler)
+    return Pair(pipe, shardings, batch)
+
+
+def reference_batches(config, traffic, *, n, batch, seed, shuffle):
+    images = image_files.reference_batches(config, traffic, n=n, batch=batch, seed=seed,
+                                           shuffle=shuffle)
+    return lambda k, dtype=np.float32: {"images": images(k, dtype), "tokens": tokens_of(k, batch)}
+
+
+def check(sample, batch_at, calibrate):
+    numbers, readings = image_files.check(
+        [(k, x["images"]) for k, x in sample],
+        lambda k, dtype=np.float32: batch_at(k, dtype)["images"], calibrate)
+    numbers["token_mismatches"] = float(sum(
+        int((x["tokens"] != batch_at(k)["tokens"]).sum()) for k, x in sample))
+    return numbers, readings
+'''
+
+_PAIR_CONSUMER = '''
+"""A step that takes a dict of both fields and waits on the tokens."""
+
+
+class Consumer:
+    setup_steps = 2
+
+    def __init__(self, config, mesh, seed, batch):
+        self.batch = batch
+
+    def compile(self, x):
+        self.dispatch(x)
+
+    def dispatch(self, x):
+        if not isinstance(x, dict) or set(x) != {"images", "tokens"}:
+            raise TypeError(f"expected a dict of images and tokens, got {type(x)}")
+        return x
+
+    def block(self, handle):
+        import jax
+
+        jax.block_until_ready(handle)
+
+    def warm_up(self, next_batch):
+        for _ in range(self.setup_steps):
+            self.block(self.dispatch(next_batch()))
+        return self.setup_steps
+
+    def free(self):
+        pass
+
+    def check(self, ctx):
+        ref = ctx.reference_batch(0)
+        return {"reference_fields_missing": float(set(ref) != {"images", "tokens"})}, {}
+'''
+
+
+@pytest.mark.parametrize("tokens_altered", [False, True])
+def test_a_two_field_feed_is_added_from_files_alone(tmp_path, monkeypatch, tokens_altered):
+    """A feed whose batches carry images and int32 tokens, its consumer,
+    configuration and cell, each a file of its own plus its entry in
+    BENCHMARK.json; no harness edit.  The consumer gets a dict, each field's
+    rows are checked for their place, and an altered token reads false."""
+    new = tiny.make_root(tmp_path / "r")
+    (new / "bench" / "feeds" / "pair.py").write_text(_PAIR_FEED)
+    (new / "bench" / "consumers" / "pair_probe.py").write_text(_PAIR_CONSUMER)
+    cfg = json.loads((new / "bench" / "configs" / "tiny-iterate.json").read_text())
+    cfg.update(name="tiny-pair", feed="pair", consumer="pair_probe")
+    cfg["limits"].update(token_mismatches=0.0, reference_fields_missing=0.0)
+    (new / "bench" / "configs" / "tiny-pair.json").write_text(json.dumps(cfg))
+    spec = json.loads((new / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny-pair", "source": "test",
+                            "file": "bench/configs/tiny-pair.json", "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "pair.1", "config": "tiny-pair", "traffic": "tiny",
+                              "chips": 1, "why": "test"})
+    (new / "BENCHMARK.json").write_text(json.dumps(spec))
+    if tokens_altered:
+        real = harness.load_module
+
+        def load(path, name):
+            mod = real(path, name)
+            if name == "bench_feed_pair":
+                honest = mod.tokens_of
+                mod.tokens_of = lambda k, batch: honest(k, batch) + 1
+                real_ref = mod.reference_batches
+
+                def ref(*args, **kw):
+                    mod.tokens_of = honest  # the reference keeps the true tokens
+                    return real_ref(*args, **kw)
+
+                mod.reference_batches = ref
+            return mod
+
+        monkeypatch.setattr(harness, "load_module", load)
+    r = harness.run_cell("pair.1", SEED, 1.5, False, t0=time.monotonic(),
+                         platform="cpu", root=new, calibrate=True)
+    assert r["checks"]["misplaced_rows"]["value"] == 0.0
+    assert r["checks"]["decode_err_ulp"]["value"] == 0.0
+    assert r["checks"]["reference_fields_missing"]["value"] == 0.0
+    assert (r["checks"]["token_mismatches"]["value"] > 0) is tokens_altered
+    assert r["correct"] is not tokens_altered, r["checks"]
